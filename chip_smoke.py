@@ -290,10 +290,11 @@ def phase_kernel(sizes: Sizes, meter: CompileMeter, mosaic: bool) -> None:
 
         def run(use_kernel):
             return lambda: jax.block_until_ready(
-                fused_auction(W, p0, eps, use_kernel=use_kernel, **kw))
+                fused_auction(W, p0, eps, use_kernel=use_kernel,
+                              with_iters=True, **kw))
 
-        (kr2c, _, kp), _, compile_s, steady_s = _timed(meter, run(True))
-        (rr2c, _, rp), _, _, ref_steady_s = _timed(meter, run(False))
+        (kr2c, _, kp, kit), _, compile_s, steady_s = _timed(meter, run(True))
+        (rr2c, _, rp, rit), _, _, ref_steady_s = _timed(meter, run(False))
         kr2c, rr2c = np.asarray(kr2c), np.asarray(rr2c)
         for tag, r2c in (("kernel", kr2c), ("reference", rr2c)):
             check(np.array_equal(np.sort(r2c), np.arange(n)),
@@ -306,10 +307,12 @@ def phase_kernel(sizes: Sizes, meter: CompileMeter, mosaic: bool) -> None:
               f"kernel/{case}: weights {w_k} vs {w_r} differ by more than "
               f"n*eps_final={slack}")
         identical = bool(np.array_equal(kr2c, rr2c)
-                         and np.array_equal(np.asarray(kp), np.asarray(rp)))
+                         and np.array_equal(np.asarray(kp), np.asarray(rp))
+                         and np.array_equal(np.asarray(kit), np.asarray(rit)))
         _line("c_kernel", case=case, n=n, B=1, matcher="auction_fused",
               use_kernel=True, compile_s=compile_s, steady_s=steady_s,
-              ref_steady_s=ref_steady_s, weight_diff=abs(w_k - w_r),
+              ref_steady_s=ref_steady_s, bid_rounds=int(np.sum(kit)),
+              weight_diff=abs(w_k - w_r),
               slack=slack, bit_identical=identical)
 
 

@@ -113,6 +113,8 @@ class _HostBatch:
         self.dec_alphas = np.asarray(res.dec.alphas, dtype=np.float64)
         self.k = np.asarray(res.dec.k)
         self.converged = np.asarray(res.dec.converged)
+        self.bid_rounds = np.asarray(res.dec.bid_rounds)
+        self.bid_rounds_run = np.asarray(res.dec.bid_rounds_run)
         self.eq_exhausted = np.asarray(res.eq_exhausted)
         self.lbs = np.asarray(res.lb, dtype=np.float64)
         # Per-instance δ (trace-aware sweeps batch mixed δs in one dispatch).
@@ -186,6 +188,10 @@ class _HostBatch:
         all_extras = {
             "k": int(self.k[b]),
             "converged": converged,
+            # This instance's bidding rounds, and the rounds the batched
+            # matcher loops ran while it was matching (see JaxDecomposition).
+            "bid_rounds": int(self.bid_rounds[b]),
+            "bid_rounds_run": int(self.bid_rounds_run[b]),
             "matcher": self.matcher,
             "use_kernel": self.use_kernel,
             "repair_rounds": self.repair_rounds,
@@ -245,9 +251,10 @@ class PendingBatch:
     XLA worker threads while the host does other work (e.g. installing the
     *previous* period's schedules — the double-buffered serving loop in
     ``repro.serve.server``). ``collect()`` performs the only
-    synchronization: the ``np.asarray`` conversions inside ``_HostBatch``
-    block on each buffer as it is read — there is no
-    ``jax.block_until_ready`` barrier anywhere on this path.
+    synchronization: it waits for the result tree once, then reads every
+    buffer, so the wait adds no barrier the reads would not make.
+    ``timings`` then holds ``collect_wait_s`` (waiting for the device) and
+    ``collect_host_s`` (transfers, materialization, validation, reports).
     """
 
     def __init__(self, res: E2EResult, mats, s, deltas, options, kwargs, t0):
@@ -259,6 +266,7 @@ class PendingBatch:
         self._kwargs = kwargs
         self._t0 = t0
         self._reports: list[SolveReport] | None = None
+        self.timings: dict[str, float] = {}
 
     def __len__(self) -> int:
         return int(self._mats.shape[0])
@@ -283,20 +291,48 @@ class PendingBatch:
                 "jax.collect",
                 {"B": len(self)} if tracer.enabled else None,
             ):
-                batch = _HostBatch(self._res, self._deltas, **self._kwargs)
-                device_s = time.perf_counter() - self._t0
-                B = len(self)
-                self._reports = [
-                    batch.report(
-                        b,
-                        Problem(self._mats[b], self._s, float(self._deltas[b])),
-                        self._options,
-                        device_s / B,
-                        extras={"batched": True, "batch_size": B, "fused": True},
-                    )
-                    for b in range(B)
-                ]
+                t_wait = time.perf_counter()
+                with tracer.span("jax.collect.wait"):
+                    jax.block_until_ready(self._res)
+                t_host = time.perf_counter()
+                with tracer.span("jax.collect.host"):
+                    batch = _HostBatch(self._res, self._deltas, **self._kwargs)
+                    device_s = time.perf_counter() - self._t0
+                    B = len(self)
+                    self._reports = [
+                        batch.report(
+                            b,
+                            Problem(
+                                self._mats[b], self._s, float(self._deltas[b])
+                            ),
+                            self._options,
+                            device_s / B,
+                            extras={
+                                "batched": True, "batch_size": B, "fused": True
+                            },
+                        )
+                        for b in range(B)
+                    ]
+            self.timings = {
+                "collect_wait_s": t_host - t_wait,
+                "collect_host_s": time.perf_counter() - t_host,
+            }
         return self._reports
+
+
+def program_text(B: int, n: int, s: int, options: SolveOptions) -> str:
+    """The optimized HLO of the fused program ``dispatch_many_jax`` runs
+    for a batch of ``B`` (n, n) matrices on ``s`` switches: the same
+    function, options and input shapes, so its instruction names are those
+    of a device trace of that batch (``repro.obs.device.op_scopes`` maps
+    them to stages). With the persistent compile cache on, the compile is
+    a cache load."""
+    return spectra_jax_e2e_many.lower(
+        jax.ShapeDtypeStruct((B, n, n), jnp.float32),
+        s,
+        jax.ShapeDtypeStruct((B,), jnp.float32),
+        **_e2e_kwargs(options, n),
+    ).compile().as_text()
 
 
 def dispatch_many_jax(

@@ -42,6 +42,10 @@ class JaxDecomposition(NamedTuple):
     alphas: jax.Array  # (n,) float32; 0 for padded rounds
     k: jax.Array       # () int32: number of real rounds
     converged: jax.Array  # () bool: all matcher calls converged
+    bid_rounds: jax.Array  # () int32: this instance's bidding rounds, summed
+                           # over its DECOMPOSE matchings
+    bid_rounds_run: jax.Array  # () int32: the rounds the (batched) loops
+                               # ran while this instance was matching
 
 
 def _decompose(
@@ -52,6 +56,7 @@ def _decompose(
     repair_rounds: int,
     carry_prices: bool,
     prices0: jax.Array | None,
+    axis_name: str | None = None,
 ) -> tuple[JaxDecomposition, jax.Array]:
     """Shared impl; returns ``(dec, final dual prices)``.
 
@@ -60,8 +65,19 @@ def _decompose(
     and seeds round 0 with ``prices0`` — the online controller's
     cross-period warm start. ``False`` reproduces the stateless behavior
     bit-for-bit (every round starts from zero prices).
+
+    ``axis_name`` names the ``vmap`` axis this call runs under, if any. A
+    batched ``while`` runs until its slowest instance stops, so each ε
+    phase of a lockstep matcher costs the batch the most rounds any
+    instance bid in it: ``bid_rounds_run`` adds that maximum (a ``pmax``
+    over the axis) where ``bid_rounds`` adds the instance's own. The fused
+    kernel runs each instance's loop in its own grid step, so there the
+    two are equal.
     """
     match = get_matcher(matcher)
+    lockstep = axis_name is not None and not (
+        use_kernel and matcher == "auction_fused"
+    )
     D = D.astype(jnp.float32)
     n = D.shape[0]
     arange = jnp.arange(n)
@@ -72,45 +88,61 @@ def _decompose(
     )
 
     def cond(st):
-        _, S_rem, _, _, i, _, _ = st
+        _, S_rem, _, _, i, _, _, _, _ = st
         return S_rem.any() & (i < n)
 
     def body(st):
-        D_rem, S_rem, perms, alphas, i, conv, prices = st
-        row_deg = S_rem.sum(axis=1)
-        col_deg = S_rem.sum(axis=0)
-        k = jnp.maximum(row_deg.max(), col_deg.max())
-        crit_r = (row_deg == k) & (k > 0)
-        crit_c = (col_deg == k) & (k > 0)
-        base = jnp.maximum(D_rem, 0.0)
-        # Dominance constant: any permutation serves at most the sum of row
-        # maxima, so this M already forces the max bonus count. (Tighter
-        # than sum(D)+1: auction prices scale with M, and float32 price
-        # resolution — hence matcher convergence — improves as M shrinks.)
-        # The bonus-level separation must dominate the matcher's n·ε
-        # optimality slack, which scales with the weight magnitude (ε is
-        # ulp-floored at wmax·2⁻²², wmax ≤ 3M) — hence the relative margin
-        # on top of the absolute +1.
-        M = (base.max(axis=1).sum() + 1.0) * (1.0 + n * 2.0**-19)
-        bonus = M * (crit_r[:, None].astype(jnp.float32) + crit_c[None, :])
-        W = base + jnp.where(S_rem, bonus, 0.0)
-        if carry_prices:
-            perm, ok, prices = match(
-                W, use_kernel=use_kernel, prices0=prices, with_prices=True
+        D_rem, S_rem, perms, alphas, i, conv, prices, rounds, run = st
+        with jax.named_scope("spectra.decompose.peel"):
+            row_deg = S_rem.sum(axis=1)
+            col_deg = S_rem.sum(axis=0)
+            k = jnp.maximum(row_deg.max(), col_deg.max())
+            crit_r = (row_deg == k) & (k > 0)
+            crit_c = (col_deg == k) & (k > 0)
+            base = jnp.maximum(D_rem, 0.0)
+            # Dominance constant: any permutation serves at most the sum of
+            # row maxima, so this M already forces the max bonus count.
+            # (Tighter than sum(D)+1: auction prices scale with M, and
+            # float32 price resolution — hence matcher convergence —
+            # improves as M shrinks.) The bonus-level separation must
+            # dominate the matcher's n·ε optimality slack, which scales
+            # with the weight magnitude (ε is ulp-floored at wmax·2⁻²²,
+            # wmax ≤ 3M) — hence the relative margin on top of the
+            # absolute +1.
+            M = (base.max(axis=1).sum() + 1.0) * (1.0 + n * 2.0**-19)
+            bonus = M * (
+                crit_r[:, None].astype(jnp.float32) + crit_c[None, :]
             )
-        else:
-            perm, ok = match(W, use_kernel=use_kernel)
-        newly = S_rem[arange, perm]
-        # α = min D_rem over *newly covered* support, exactly the numpy
-        # "covered_support" rule: a round that newly covers nothing gets α=0
-        # (guarding on newly.any() keeps the inf mask from ever escaping).
-        vals = jnp.where(newly, D_rem[arange, perm], jnp.inf)
-        alpha = jnp.where(newly.any(), vals.min(), 0.0)
-        D_rem = jnp.maximum(D_rem.at[arange, perm].add(-alpha), 0.0)
-        S_rem = S_rem.at[arange, perm].set(False)
-        perms = perms.at[i].set(perm.astype(jnp.int32))
-        alphas = alphas.at[i].set(alpha)
-        return D_rem, S_rem, perms, alphas, i + 1, conv & ok, prices
+            W = base + jnp.where(S_rem, bonus, 0.0)
+        with jax.named_scope("spectra.decompose.match"):
+            if carry_prices:
+                perm, ok, prices, iters = match(
+                    W, use_kernel=use_kernel, prices0=prices,
+                    with_prices=True, with_iters=True,
+                )
+            else:
+                perm, ok, iters = match(
+                    W, use_kernel=use_kernel, with_iters=True
+                )
+            rounds = rounds + iters.sum()
+            if lockstep:
+                iters = jax.lax.pmax(iters, axis_name)
+            run = run + iters.sum()
+        with jax.named_scope("spectra.decompose.peel"):
+            newly = S_rem[arange, perm]
+            # α = min D_rem over *newly covered* support, exactly the numpy
+            # "covered_support" rule: a round that newly covers nothing gets
+            # α=0 (guarding on newly.any() keeps the inf mask from ever
+            # escaping).
+            vals = jnp.where(newly, D_rem[arange, perm], jnp.inf)
+            alpha = jnp.where(newly.any(), vals.min(), 0.0)
+            D_rem = jnp.maximum(D_rem.at[arange, perm].add(-alpha), 0.0)
+            S_rem = S_rem.at[arange, perm].set(False)
+            perms = perms.at[i].set(perm.astype(jnp.int32))
+            alphas = alphas.at[i].set(alpha)
+        return (
+            D_rem, S_rem, perms, alphas, i + 1, conv & ok, prices, rounds, run
+        )
 
     init = (
         D,
@@ -120,8 +152,10 @@ def _decompose(
         jnp.int32(0),
         jnp.bool_(True),
         init_prices,
+        jnp.int32(0),
+        jnp.int32(0),
     )
-    D_rem, S_rem, perms, alphas, k, conv, prices = jax.lax.while_loop(
+    _, _, perms, alphas, k, conv, prices, rounds, run = jax.lax.while_loop(
         cond, body, init
     )
 
@@ -130,9 +164,6 @@ def _decompose(
 
     def coverage(al):
         return jnp.zeros_like(D).at[cov_idx].add(al[:, None] * round_live)
-
-    # Greedy REFINE (Alg. 2) over all rounds (padded rounds see zero residual).
-    R0 = jnp.maximum(D - coverage(alphas), 0.0)
 
     def refine_body(r, carry):
         R, alphas = carry
@@ -144,18 +175,26 @@ def _decompose(
         R = jnp.maximum(R, 0.0)
         return R, alphas
 
-    _, alphas = jax.lax.fori_loop(0, n, refine_body, (R0, alphas))
+    # Greedy REFINE (Alg. 2) over all rounds (padded rounds see zero residual).
+    with jax.named_scope("spectra.refine"):
+        R0 = jnp.maximum(D - coverage(alphas), 0.0)
+        _, alphas = jax.lax.fori_loop(0, n, refine_body, (R0, alphas))
 
     if repair_rounds:
-        perms, alphas, k = _repair(
-            D, perms, alphas, k, coverage, repair_rounds
-        )
-    dec = JaxDecomposition(perms=perms, alphas=alphas, k=k, converged=conv)
+        with jax.named_scope("spectra.repair"):
+            perms, alphas, k = _repair(
+                D, perms, alphas, k, coverage, repair_rounds
+            )
+    dec = JaxDecomposition(
+        perms=perms, alphas=alphas, k=k, converged=conv,
+        bid_rounds=rounds, bid_rounds_run=run,
+    )
     return dec, prices
 
 
 @functools.partial(
-    jax.jit, static_argnames=("use_kernel", "matcher", "repair_rounds")
+    jax.jit,
+    static_argnames=("use_kernel", "matcher", "repair_rounds", "axis_name"),
 )
 def decompose_jax(
     D: jax.Array,
@@ -163,16 +202,19 @@ def decompose_jax(
     use_kernel: bool = False,
     matcher: str = "auction",
     repair_rounds: int = 0,
+    axis_name: str | None = None,
 ) -> JaxDecomposition:
     """Exactly-k decomposition of D (Alg. 1 + greedy REFINE), on device.
 
     ``matcher`` picks the device MWM solver from ``matching.MATCHERS``;
     ``repair_rounds`` bounds the post-REFINE local-search sweeps (0 keeps
-    the paper-faithful Alg. 1+2 output bit-for-bit).
+    the paper-faithful Alg. 1+2 output bit-for-bit). ``axis_name`` names
+    the ``vmap`` axis of a batched call (see ``_decompose``).
     """
     dec, _ = _decompose(
         D, use_kernel=use_kernel, matcher=matcher,
         repair_rounds=repair_rounds, carry_prices=False, prices0=None,
+        axis_name=axis_name,
     )
     return dec
 

@@ -6,6 +6,10 @@ single XLA program emitting a dense ``DeviceSchedule``; ``spectra_jax_e2e_many``
 is its ``vmap`` over stacked demand matrices — the controller path that
 re-solves scheduling for many concurrent demand matrices per period without
 a host round-trip between stages.
+
+Each stage runs under a ``jax.named_scope`` from ``repro.obs.device.SCOPES``
+(``spectra.decompose.*``, ``spectra.lpt``, ``spectra.equalize``,
+``spectra.lb``), so a device trace can be split by stage.
 """
 
 from __future__ import annotations
@@ -42,7 +46,7 @@ def _ir_makespan(ds: DeviceSchedule, s: int) -> jax.Array:
     jax.jit,
     static_argnames=(
         "s", "use_kernel", "do_equalize", "merge_aware", "extra_slots",
-        "matcher", "repair_rounds",
+        "matcher", "repair_rounds", "axis_name",
     ),
 )
 def spectra_jax_e2e(
@@ -56,21 +60,26 @@ def spectra_jax_e2e(
     extra_slots: int = 64,
     matcher: str = "auction",
     repair_rounds: int = 0,
+    axis_name: str | None = None,
 ) -> E2EResult:
     """Full SPECTRA pipeline for one (n, n) demand matrix, entirely on device.
 
     ``extra_slots`` is the EQUALIZE split headroom appended to the n
     decomposition slots (each non-merging split consumes one slot).
     ``matcher`` selects the device MWM solver (``matching.MATCHERS``);
-    ``repair_rounds`` bounds the post-REFINE local-search sweeps.
+    ``repair_rounds`` bounds the post-REFINE local-search sweeps;
+    ``axis_name`` names the ``vmap`` axis when batched (it feeds
+    ``dec.bid_rounds_run``).
     """
     D = jnp.asarray(D, jnp.float32)
     n = D.shape[0]
     delta = jnp.asarray(delta, jnp.float32)
     dec = decompose_jax(
-        D, use_kernel=use_kernel, matcher=matcher, repair_rounds=repair_rounds
+        D, use_kernel=use_kernel, matcher=matcher,
+        repair_rounds=repair_rounds, axis_name=axis_name,
     )
-    assignment, _, lpt_makespan = lpt_schedule_jax(dec, s, delta)
+    with jax.named_scope("spectra.lpt"):
+        assignment, _, lpt_makespan = lpt_schedule_jax(dec, s, delta)
     pad_perms = jnp.broadcast_to(
         jnp.arange(n, dtype=jnp.int32)[None, :], (extra_slots, n)
     )
@@ -84,14 +93,17 @@ def spectra_jax_e2e(
     )
     eq_exhausted = jnp.bool_(False)
     if do_equalize:
-        ds, eq_exhausted = equalize_ir(ds, s, merge_aware=merge_aware)
+        with jax.named_scope("spectra.equalize"):
+            ds, eq_exhausted = equalize_ir(ds, s, merge_aware=merge_aware)
+    with jax.named_scope("spectra.lb"):
+        lb = lower_bound_jax(D, s, delta)
     return E2EResult(
         schedule=ds,
         dec=dec,
         makespan=_ir_makespan(ds, s),
         lpt_makespan=lpt_makespan,
         eq_exhausted=eq_exhausted,
-        lb=lower_bound_jax(D, s, delta),
+        lb=lb,
     )
 
 
@@ -120,6 +132,7 @@ def spectra_jax_e2e_many(
     (per-instance δ — how trace-aware δ sweeps batch a whole trace whose
     reconfiguration delay varies per period into one dispatch).
     """
+    axis = "batch"
     Ds = jnp.asarray(Ds, jnp.float32)
     deltas = jnp.broadcast_to(
         jnp.asarray(delta, jnp.float32), (Ds.shape[0],)
@@ -135,5 +148,7 @@ def spectra_jax_e2e_many(
             extra_slots=extra_slots,
             matcher=matcher,
             repair_rounds=repair_rounds,
-        )
+            axis_name=axis,
+        ),
+        axis_name=axis,
     )(Ds, deltas)

@@ -164,6 +164,14 @@ def _forward_round(W, row2col, col2row, prices, profits, eps, use_kernel):
     round serves as one side of the forward-reverse matcher; the plain
     forward matcher threads a zero array through unchanged cost.
     """
+    with jax.named_scope("auction.forward_round"):
+        return _bid_round(
+            W, row2col, col2row, prices, profits, eps, use_kernel
+        )
+
+
+def _bid_round(W, row2col, col2row, prices, profits, eps, use_kernel):
+    """The round of ``_forward_round``, outside any scope."""
     n = W.shape[0]
     arange = jnp.arange(n)
     unassigned = row2col < 0
@@ -193,9 +201,10 @@ def _forward_round(W, row2col, col2row, prices, profits, eps, use_kernel):
 def _reverse_round(W, row2col, col2row, prices, profits, eps, use_kernel):
     """Column-side bidding: the forward round on ``W.T`` with roles swapped
     (prices ↔ profits), sharing the same top-2 reduction."""
-    col2row, row2col, profits, prices = _forward_round(
-        W.T, col2row, row2col, profits, prices, eps, use_kernel
-    )
+    with jax.named_scope("auction.reverse_round"):
+        col2row, row2col, profits, prices = _bid_round(
+            W.T, col2row, row2col, profits, prices, eps, use_kernel
+        )
     return row2col, col2row, prices, profits
 
 
@@ -220,8 +229,21 @@ def _eps_schedule(W, num_phases: int):
     return (wmax / 2.0) * ratio ** jnp.arange(num_phases)
 
 
+def _returns(perm, converged, prices, iters, with_prices, with_iters):
+    """``(perm, converged)``, then the prices and the rounds per phase
+    when asked for, in that order."""
+    return (
+        (perm, converged)
+        + ((prices,) if with_prices else ())
+        + ((iters,) if with_iters else ())
+    )
+
+
 @functools.partial(
-    jax.jit, static_argnames=("num_phases", "max_iters", "use_kernel", "with_prices")
+    jax.jit,
+    static_argnames=(
+        "num_phases", "max_iters", "use_kernel", "with_prices", "with_iters",
+    ),
 )
 def match_auction(
     W: jax.Array,
@@ -231,6 +253,7 @@ def match_auction(
     use_kernel: bool = False,
     prices0: jax.Array | None = None,
     with_prices: bool = False,
+    with_iters: bool = False,
 ) -> tuple[jax.Array, ...]:
     """Forward ε-scaling auction. Returns ``(perm, converged)``.
 
@@ -240,7 +263,8 @@ def match_auction(
     phase, so a warm start is equivalent to having run one extra earlier
     phase: optimality is unaffected, convergence on near-repeated instances
     is faster. ``with_prices=True`` appends the final prices to the return
-    for callers that carry them forward.
+    for callers that carry them forward; ``with_iters=True`` appends the
+    bidding rounds of each ε phase, ``(num_phases,)`` int32, after them.
     """
     W = W.astype(jnp.float32)
     n = W.shape[0]
@@ -272,27 +296,28 @@ def match_auction(
             )
             return row2col, col2row, prices, it + 1
 
-        row2col, col2row, prices, _ = jax.lax.while_loop(
-            cond, body, (row2col, col2row, prices, 0)
+        row2col, col2row, prices, it = jax.lax.while_loop(
+            cond, body, (row2col, col2row, prices, jnp.int32(0))
         )
-        return (row2col, col2row, prices), None
+        return (row2col, col2row, prices), it
 
     state = (
         jnp.full((n,), -1, jnp.int32),
         jnp.full((n,), -1, jnp.int32),
         init_prices,
     )
-    state, _ = jax.lax.scan(phase, state, _eps_schedule(W, num_phases))
+    state, iters = jax.lax.scan(phase, state, _eps_schedule(W, num_phases))
     row2col, col2row, prices = state
     converged = (row2col >= 0).all()
     perm = _complete_greedy(row2col, col2row)
-    if with_prices:
-        return perm, converged, prices
-    return perm, converged
+    return _returns(perm, converged, prices, iters, with_prices, with_iters)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("num_phases", "max_iters", "use_kernel", "with_prices")
+    jax.jit,
+    static_argnames=(
+        "num_phases", "max_iters", "use_kernel", "with_prices", "with_iters",
+    ),
 )
 def match_auction_fr(
     W: jax.Array,
@@ -302,14 +327,15 @@ def match_auction_fr(
     use_kernel: bool = False,
     prices0: jax.Array | None = None,
     with_prices: bool = False,
+    with_iters: bool = False,
 ) -> tuple[jax.Array, ...]:
     """Combined forward-reverse auction. Returns ``(perm, converged)``.
 
     Rows and columns take turns bidding; the side flips whenever a round
     grows the assignment (Bertsekas-Castañón switching rule — the matched
     count never shrinks, so alternation cannot cycle). ``prices0`` /
-    ``with_prices`` behave as on ``match_auction`` (warm-started column
-    prices in, final prices out).
+    ``with_prices`` / ``with_iters`` behave as on ``match_auction``
+    (warm-started column prices in, final prices and rounds per phase out).
     """
     W = W.astype(jnp.float32)
     n = W.shape[0]
@@ -344,10 +370,12 @@ def match_auction_fr(
             grew = (row2col >= 0).sum() > before
             return row2col, col2row, prices, profits, fwd ^ grew, it + 1
 
-        row2col, col2row, prices, profits, _, _ = jax.lax.while_loop(
-            cond, body, (row2col, col2row, prices, profits, jnp.bool_(True), 0)
+        row2col, col2row, prices, profits, _, it = jax.lax.while_loop(
+            cond,
+            body,
+            (row2col, col2row, prices, profits, jnp.bool_(True), jnp.int32(0)),
         )
-        return (row2col, col2row, prices, profits), None
+        return (row2col, col2row, prices, profits), it
 
     state = (
         jnp.full((n,), -1, jnp.int32),
@@ -355,13 +383,11 @@ def match_auction_fr(
         init_prices,
         jnp.zeros((n,), jnp.float32),
     )
-    state, _ = jax.lax.scan(phase, state, _eps_schedule(W, num_phases))
+    state, iters = jax.lax.scan(phase, state, _eps_schedule(W, num_phases))
     row2col, col2row, prices, _ = state
     converged = (row2col >= 0).all()
     perm = _complete_greedy(row2col, col2row)
-    if with_prices:
-        return perm, converged, prices
-    return perm, converged
+    return _returns(perm, converged, prices, iters, with_prices, with_iters)
 
 
 def _polish_2swap(W, perm, max_swaps: int):
@@ -441,10 +467,10 @@ def match_auction_fused(
     2-opt — a cheap worst-case guard against ε-floor transposition errors
     (measured a no-op on the benchmark workloads; see its docstring).
     ``interpret`` forces/disables Pallas interpret mode (``None`` → auto:
-    interpret off-TPU). ``with_iters=True`` appends the total bidding-round
-    count (after prices, when both are requested) — the observable that
-    shows cross-period warm starts converging in fewer rounds; the kernel
-    path reports ``-1`` (its loop counter stays on-chip).
+    interpret off-TPU). ``with_iters=True`` appends the bidding rounds of
+    each ε phase run (after prices, when both are requested), from the
+    kernel or the reference alike — the observable that shows cross-period
+    warm starts converging in fewer rounds.
 
     **Warm ε-entry:** supplying ``prices0`` is declared "equivalent to
     having run the earlier phases already" (see ``match_auction``) — here
@@ -484,12 +510,8 @@ def match_auction_fused(
     converged = (row2col >= 0).all()
     perm = _complete_greedy(row2col, col2row)
     perm = _polish_2swap(W, perm, max_swaps=2 * n)
-    ret: tuple[jax.Array, ...] = (perm, converged)
-    if with_prices:
-        ret = ret + (prices,)
-    if with_iters:
-        ret = ret + (out[3],)
-    return ret if len(ret) > 2 else (perm, converged)
+    iters = out[3] if with_iters else None
+    return _returns(perm, converged, prices, iters, with_prices, with_iters)
 
 
 # --------------------------------------------------------------- registry
@@ -514,11 +536,13 @@ def list_matchers() -> list[str]:
 
 
 def register_matcher(name: str, fn: MatcherFn, *, overwrite: bool = False) -> None:
-    """Add a device matcher: ``fn(W, *, num_phases, max_iters, use_kernel)
-    -> (perm, converged)``, jittable and vmappable. Matchers that support
-    warm starts additionally accept ``prices0`` (initial dual prices) and
-    ``with_prices=True`` (append final prices to the return) — the online
-    controller only requests those from matchers that advertise them."""
+    """Add a device matcher: ``fn(W, *, num_phases, max_iters, use_kernel,
+    with_iters) -> (perm, converged[, rounds per ε phase])``, jittable and
+    vmappable; DECOMPOSE always asks for the rounds (``with_iters=True``).
+    Matchers that support warm starts additionally accept ``prices0``
+    (initial dual prices) and ``with_prices=True`` (append final prices,
+    before the rounds) — the online controller only requests those from
+    matchers that advertise them."""
     if name in MATCHERS and not overwrite:
         raise ValueError(f"matcher {name!r} already registered")
     replacing = name in MATCHERS

@@ -55,6 +55,11 @@ Per-period algorithm (``online_step_jax``):
    configuration it serves (slot-index order, reused config first, EQUALIZE
    splits last).
 
+Stages run under the ``jax.named_scope`` names of ``repro.obs.device``:
+the stateless pipeline's where the stage is the same (``spectra.lpt``,
+``spectra.equalize``, ``spectra.lb`` and DECOMPOSE's), and
+``online.warm_refine``, ``online.reuse_lpt`` and ``online.credit``.
+
 ``spectra_online_scan`` rolls the step over a whole (T, n, n) trace under
 ``lax.scan`` with the switch state as carry: an entire training run's
 scheduling is ONE device dispatch, no host round-trips between periods.
@@ -338,20 +343,23 @@ def _online_step(
         the warm excess to ``warm_slack``; the round count may not exceed
         degree(D) (a fresh decomposition's exact k) either.
         """
-        alphas_w, residual = _warm_refine(D, perms, k)
-        covered = residual.max() <= 1e-5 * jnp.maximum(D.max(), 1e-30)
-        live = alphas_w > 0
-        order = jnp.argsort(~live, stable=True)
-        dec_ = JaxDecomposition(
-            perms=perms[order],
-            alphas=jnp.where(live, alphas_w, 0.0)[order],
-            k=live.sum().astype(jnp.int32),
-            converged=jnp.bool_(True),
-        )
-        warm_ratio = alphas_w.sum() / line_sum_safe
-        quality_ok = (
-            (dec_.k <= deg) & (warm_ratio <= ref_ratio * (1.0 + warm_slack))
-        )
+        with jax.named_scope("online.warm_refine"):
+            alphas_w, residual = _warm_refine(D, perms, k)
+            covered = residual.max() <= 1e-5 * jnp.maximum(D.max(), 1e-30)
+            live = alphas_w > 0
+            order = jnp.argsort(~live, stable=True)
+            dec_ = JaxDecomposition(
+                perms=perms[order],
+                alphas=jnp.where(live, alphas_w, 0.0)[order],
+                k=live.sum().astype(jnp.int32),
+                converged=jnp.bool_(True),
+                bid_rounds=jnp.int32(0),
+                bid_rounds_run=jnp.int32(0),
+            )
+            warm_ratio = alphas_w.sum() / line_sum_safe
+            quality_ok = (dec_.k <= deg) & (
+                warm_ratio <= ref_ratio * (1.0 + warm_slack)
+            )
         return dec_, covered & quality_ok
 
     if warm_start:
@@ -396,25 +404,36 @@ def _online_step(
 
     # ---- 2+3. two candidates over the same decomposition -----------------
     # A: plain LPT + uncredited EQUALIZE — the stateless reference.
-    assignment_a, _, _ = lpt_schedule_jax(dec, s, delta)
+    with jax.named_scope("spectra.lpt"):
+        assignment_a, _, _ = lpt_schedule_jax(dec, s, delta)
     ds_a = _build_table(dec, assignment_a, delta, extra_slots)
     # B: reuse-then-LPT + EQUALIZE on credited loads.
-    assignment_b, reused_rounds = _reuse_then_lpt(dec, state.installed, s, delta)
+    with jax.named_scope("online.reuse_lpt"):
+        assignment_b, reused_rounds = _reuse_then_lpt(
+            dec, state.installed, s, delta
+        )
     ds_b = _build_table(dec, assignment_b, delta, extra_slots)
-    _, has_b = _switch_credit(
-        ds_b.perms, ds_b.switch, state.installed, s
-    )
+    with jax.named_scope("online.credit"):
+        _, has_b = _switch_credit(
+            ds_b.perms, ds_b.switch, state.installed, s
+        )
     ex_a = ex_b = jnp.bool_(False)
     if do_equalize:
-        ds_a, ex_a = equalize_ir(ds_a, s, merge_aware=merge_aware)
-        ds_b, ex_b = equalize_ir(
-            ds_b, s, merge_aware=merge_aware, load_offset=-delta * has_b
-        )
+        with jax.named_scope("spectra.equalize"):
+            ds_a, ex_a = equalize_ir(ds_a, s, merge_aware=merge_aware)
+            ds_b, ex_b = equalize_ir(
+                ds_b, s, merge_aware=merge_aware, load_offset=-delta * has_b
+            )
 
     # ---- 4. best-of selection (credit applied to both final tables) ------
     stateless_mk = device_loads(ds_a.alphas, ds_a.switch, delta, s).max()
-    mk_a, reused_a, has_a = _credited_makespan(ds_a, state.installed, s, delta)
-    mk_b, reused_b, has_b_f = _credited_makespan(ds_b, state.installed, s, delta)
+    with jax.named_scope("online.credit"):
+        mk_a, reused_a, has_a = _credited_makespan(
+            ds_a, state.installed, s, delta
+        )
+        mk_b, reused_b, has_b_f = _credited_makespan(
+            ds_b, state.installed, s, delta
+        )
     use_b = mk_b <= mk_a
     ds = jax.tree_util.tree_map(
         lambda b, a: jnp.where(use_b, b, a), ds_b, ds_a
@@ -469,6 +488,8 @@ def _online_step(
         cache_ratio=cache_ratio,
         cache_ptr=cache_ptr,
     )
+    with jax.named_scope("spectra.lb"):
+        lb = lower_bound_jax(D, s, delta)
     result = OnlineStepResult(
         schedule=ds,
         reused=reused,
@@ -476,7 +497,7 @@ def _online_step(
         stateless_makespan=stateless_mk,
         reuse_count=reuse_count,
         warm=use_warm,
-        lb=lower_bound_jax(D, s, delta),
+        lb=lb,
         k=dec.k,
         converged=dec.converged,
         eq_exhausted=eq_exhausted,

@@ -6,7 +6,7 @@ every round round-trips through XLA and re-materializes whole-matrix
 intermediates. This kernel owns the loop instead:
 
 * **grid = (num_phases,)** — the ε-scaling phase axis; the ε schedule sits
-  in SMEM. Column dual prices live in the resident price output block and
+  in SMEM, and so does the output that counts each phase's bidding rounds. Column dual prices live in the resident price output block and
   persist across grid steps (seeded from the warm-start input on phase 0);
   each phase restarts the assignment maps and bids until complete, exactly
   the ε-scaling restart semantics of the registry matchers.
@@ -66,6 +66,7 @@ def _fused_auction_kernel(
     r2c_ref,      # out (n_pad, 1) i32 — row → column, per-row column layout
     c2r_ref,      # out (1, n_pad) i32 — column → row, per-column row layout
     price_ref,    # out (1, n_pad) f32 — column prices, carried across phases
+    iters_ref,    # out SMEM (1, num_phases) i32 — bidding rounds per phase
     *,
     n: int,
     n_pad: int,
@@ -161,7 +162,8 @@ def _fused_auction_kernel(
         return it + 1, unassigned
 
     # n ≥ 1 real row starts unassigned, so the first round always runs.
-    jax.lax.while_loop(cond, body, (jnp.int32(0), jnp.int32(1)))
+    it, _ = jax.lax.while_loop(cond, body, (jnp.int32(0), jnp.int32(1)))
+    iters_ref[0, pid] = it
 
 
 @functools.partial(
@@ -177,8 +179,9 @@ def fused_auction_pallas(
     max_iters: int,
     interpret: bool = False,
 ):
-    """Returns ``(r2c (n_pad, 1), c2r (1, n_pad), prices (1, n_pad))``;
-    rows/columns ``>= n`` are padding."""
+    """Returns ``(r2c (n_pad, 1), c2r (1, n_pad), prices (1, n_pad),
+    iters (1, num_phases))``; rows/columns ``>= n`` are padding, and
+    ``iters`` holds the bidding rounds of each phase."""
     n_pad = W.shape[0]
     if n_pad % block_cols:
         raise ValueError(
@@ -205,11 +208,13 @@ def fused_auction_pallas(
             pl.BlockSpec((n_pad, 1), lambda p: (0, 0)),
             row_spec,
             row_spec,
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
             jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
             jax.ShapeDtypeStruct((1, n_pad), W.dtype),
+            jax.ShapeDtypeStruct((1, num_phases), jnp.int32),
         ),
         interpret=interpret,
     )(eps_schedule, W, prices0)

@@ -46,10 +46,9 @@ def fused_auction(
     """Run the fused ε-scaling auction; returns ``(r2c, c2r, prices)`` at
     the caller's (unpadded) n. ``interpret=None`` → auto (off on TPU).
 
-    ``with_iters=True`` appends the total bidding-round count. The Pallas
-    kernel keeps its loop counter on-chip and doesn't export it, so the
-    kernel path reports ``-1`` ("not tracked"); the jnp reference reports
-    the exact count — that is the path warm-start round assertions use.
+    ``with_iters=True`` appends the bidding rounds of each ε phase,
+    ``(num_phases,)`` int32; the kernel and the reference count the same
+    rounds.
     """
     if not use_kernel:
         return fused_auction_ref(
@@ -67,7 +66,7 @@ def fused_auction(
     p0 = jnp.pad(jnp.asarray(prices0, jnp.float32), (0, pad))[None, :]
     if block_cols is None:
         block_cols = _LANE if n_pad >= 256 else n_pad
-    r2c, c2r, prices = fused_auction_pallas(
+    r2c, c2r, prices, iters = fused_auction_pallas(
         Wp,
         p0,
         jnp.asarray(eps_schedule, jnp.float32)[None, :],
@@ -78,5 +77,5 @@ def fused_auction(
     )
     r2c, c2r, prices = r2c[:, 0], c2r[0], prices[0]
     if with_iters:
-        return r2c[:n], c2r[:n], prices[:n], jnp.int32(-1)
+        return r2c[:n], c2r[:n], prices[:n], iters[0]
     return r2c[:n], c2r[:n], prices[:n]

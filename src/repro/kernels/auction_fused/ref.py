@@ -67,8 +67,9 @@ def fused_auction_ref(
 
     Each phase restarts the assignment maps from scratch but keeps the
     learned prices — identical to the kernel's per-phase grid steps.
-    ``with_iters=True`` appends the total bidding-round count summed over
-    phases — the convergence-cost observable warm-started prices reduce.
+    ``with_iters=True`` appends the bidding rounds of each phase, counted
+    as the kernel counts them — the convergence-cost observable
+    warm-started prices reduce.
     """
     W = W.astype(jnp.float32)
     n = W.shape[0]
@@ -106,5 +107,5 @@ def fused_auction_ref(
     )
     (r2c, c2r, prices), phase_iters = jax.lax.scan(phase, state, eps_schedule)
     if with_iters:
-        return r2c, c2r, prices, phase_iters.sum()
+        return r2c, c2r, prices, phase_iters
     return r2c, c2r, prices

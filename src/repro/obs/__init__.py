@@ -1,12 +1,17 @@
 """Observability: span tracing, time-expanded switch tables, shared metrics.
 
-Three pillars, one package:
+Four pillars, one package:
 
 * ``trace`` — a lightweight nested-span tracer wired through the whole
-  pipeline (API dispatch/collect, shape buckets, decompose/LPT/equalize
-  stages, matcher rounds, the serving batch loop, scenario periods).
-  Disabled it costs one attribute check per call site; enabled it exports
-  Chrome trace-event JSON viewable in Perfetto (``chrome://tracing``).
+  pipeline (``solve_many`` and its shape buckets, the host
+  decompose/schedule/equalize stages and matchings, scenario periods and
+  installs, the served flights' dispatch / collect / install). Disabled it
+  costs one attribute check per call site; enabled it exports Chrome
+  trace-event JSON viewable in Perfetto (``chrome://tracing``) and writes
+  each span into a running JAX profiler's trace.
+* ``device`` — the ``jax.named_scope`` names of the fused device
+  program's stages, and the map from a compiled program's instructions to
+  them (a device trace names only instructions).
 * ``timeline_table`` — the time-expanded view of a schedule built on
   ``repro.fabric.timeline``: per-switch occupancy rows (serve /
   reconfigure / idle intervals), per-round utilization, and the makespan

@@ -1,11 +1,22 @@
 """Nested-span tracer with Chrome trace-event export.
 
-The tracer the whole pipeline is wired through: ``span("decompose")``
-around each stage, ``span("matcher")`` around each matching round,
-``span("jax.dispatch")`` / ``span("jax.collect")`` around the fused device
-calls, ``span("serve.install")`` around switch programming, and so on.
-One module-level default tracer (``get_tracer()``) is what the wiring
-uses; tests and tools may construct their own ``Tracer``.
+The tracer the whole pipeline is wired through. The spans that exist:
+
+* host pipeline (``repro.api``, ``repro.scenarios``): ``solve_many`` and
+  its ``bucket``s, the stages ``decompose`` / ``schedule`` / ``equalize``,
+  ``matcher`` around each host matching, ``period`` / ``online.period``
+  and ``install`` per scenario period;
+* served device path (``repro.serve.server``, ``repro.api.jax_backend``):
+  ``serve.dispatch`` and ``serve.install`` per flight, both carrying the
+  flight's sequence number (``flight``), with ``jax.dispatch`` and
+  ``jax.collect`` nested under them; ``jax.collect`` splits into
+  ``jax.collect.wait`` (until the device results are ready) and
+  ``jax.collect.host`` (transfers, materialization, validation, reports).
+
+Stages *inside* the fused device program are not host spans: they are
+``jax.named_scope`` names (``repro.obs.device.SCOPES``) that a device
+trace carries. One module-level default tracer (``get_tracer()``) is what
+the wiring uses; tests and tools may construct their own ``Tracer``.
 
 Cost discipline — the tracer is wired into hot paths, so:
 
@@ -15,9 +26,12 @@ Cost discipline — the tracer is wired into hot paths, so:
   dicts guard on ``tracer.enabled`` (or pass ``args`` only when cheap) so
   the disabled path stays allocation-free end to end.
 * **Enabled**, each span costs two ``perf_counter`` reads, one small
-  object, and one list append. Spans nest via a per-thread stack; every
-  finished span records its parent, so containment invariants
-  (child ⊆ parent interval) are checkable directly.
+  object, one list append, and a ``jax.profiler.TraceAnnotation`` of the
+  same name (and args), so that under a running JAX profiler every span is
+  also a host event of the profiler's own trace, on the clock of the
+  device operations. Spans nest via a per-thread stack; every finished
+  span records its parent, so containment invariants (child ⊆ parent
+  interval) are checkable directly.
 
 Export is the Chrome trace-event JSON format (``ph: "X"`` complete
 events, microsecond timestamps) — load the file in Perfetto
@@ -34,6 +48,8 @@ import threading
 import time
 from pathlib import Path
 from typing import Any, Mapping
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["SpanEvent", "Tracer", "get_tracer", "span"]
 
@@ -87,19 +103,24 @@ _NULL_SPAN = _NullSpan()
 
 
 class _LiveSpan:
-    """Context manager recording one SpanEvent on the owning tracer."""
+    """Context manager recording one SpanEvent on the owning tracer, and
+    holding the profiler annotation of the same name open meanwhile."""
 
-    __slots__ = ("_tracer", "_index")
+    __slots__ = ("_tracer", "_index", "_annotation")
 
-    def __init__(self, tracer: "Tracer", index: int) -> None:
+    def __init__(
+        self, tracer: "Tracer", index: int, annotation: TraceAnnotation
+    ) -> None:
         self._tracer = tracer
         self._index = index
+        self._annotation = annotation
 
     def __enter__(self) -> "_LiveSpan":
         return self
 
     def __exit__(self, *exc) -> bool:
         self._tracer._close(self._index)
+        self._annotation.__exit__(*exc)
         return False
 
     def set(self, **kw) -> None:
@@ -146,12 +167,16 @@ class Tracer:
         """Context manager timing one nested span.
 
         ``args`` (an optional mapping) lands in the exported event's
-        ``args`` field. When the tracer is disabled this returns a shared
-        no-op singleton — build arg dicts only under ``tracer.enabled``
-        if the call site is hot.
+        ``args`` field, and in the profiler annotation's metadata. When the
+        tracer is disabled this returns a shared no-op singleton — build
+        arg dicts only under ``tracer.enabled`` if the call site is hot.
+        The span (and its annotation) opens at this call, so use it in a
+        ``with`` statement straight away.
         """
         if not self.enabled:
             return _NULL_SPAN
+        annotation = TraceAnnotation(name, **(args or {}))
+        annotation.__enter__()
         stack = self._stack()
         ev = SpanEvent(
             name,
@@ -164,7 +189,7 @@ class Tracer:
         index = len(self.events)
         self.events.append(ev)
         stack.append(index)
-        return _LiveSpan(self, index)
+        return _LiveSpan(self, index, annotation)
 
     def _close(self, index: int) -> None:
         self.events[index].end = time.perf_counter()
@@ -176,17 +201,6 @@ class Tracer:
             j = stack.pop()
             if self.events[j].end is None:
                 self.events[j].end = self.events[index].end
-
-    def instant(self, name: str, args: Mapping[str, Any] | None = None) -> None:
-        """Point-in-time marker (Chrome ``ph: "i"`` instant event)."""
-        if not self.enabled:
-            return
-        ev = SpanEvent(
-            name, "repro.instant", time.perf_counter(), None,
-            threading.get_ident(), dict(args) if args else None,
-        )
-        ev.end = ev.start
-        self.events.append(ev)
 
     def counter(self, name: str, value: float) -> None:
         """Time-series counter sample (Chrome ``ph: "C"`` counter event)."""
@@ -201,7 +215,7 @@ class Tracer:
 
     # ------------------------------------------------------------ export
     def spans(self) -> list[SpanEvent]:
-        """Finished spans (open spans and instant/counter samples excluded)."""
+        """Finished spans (open spans and counter samples excluded)."""
         return [
             e for e in self.events
             if e.cat == "repro" and e.end is not None
@@ -223,9 +237,7 @@ class Tracer:
             }
             if e.args:
                 common["args"] = dict(e.args)
-            if e.cat == "repro.instant":
-                common.update(ph="i", s="t")
-            elif e.cat == "repro.counter":
+            if e.cat == "repro.counter":
                 common.update(ph="C")
             else:
                 common.update(ph="X", dur=(e.end - e.start) * 1e6)

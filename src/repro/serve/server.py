@@ -14,8 +14,8 @@ controller pays solve + install every cycle; this server overlaps them:
     inflight = batch t+1
 
 so the steady-state cycle costs max(solve, install) instead of their sum.
-There is no ``jax.block_until_ready`` anywhere in the handoff — the only
-synchronization is ``PendingBatch.collect()`` reading the result buffers.
+The only synchronization in the handoff is ``PendingBatch.collect()``,
+which waits for the result buffers it then reads.
 ``mode="sync"`` dispatches and installs back to back (identical results,
 serial timing); it is the only mode for host solvers, which have no
 dispatch/collect split — asking them for ``"async"`` raises.
@@ -58,6 +58,7 @@ class _Request:
 class _Inflight:
     """One dispatched batch: device work plus its cache-served siblings."""
 
+    seq: int  # the flight's sequence number in this server
     device_reqs: list[_Request]
     pending: "PendingBatch | None"  # None → host solver runs at install
     cached: list[tuple[_Request, CacheResult]]
@@ -76,6 +77,7 @@ class ServeResult:
     num_configs: int
     degraded: bool
     report: SolveReport | None
+    flight: int  # sequence number of the batch that answered it
     timings: dict[str, float] = field(default_factory=dict)
 
 
@@ -126,6 +128,7 @@ class ScheduleServer:
         self._rr = 0
         self._inflight: _Inflight | None = None
         self._next_ticket = 0
+        self._next_flight = 0
         self.results: dict[int, ServeResult] = {}
         self.shed_tickets: list[int] = []
         self._degraded_options = SolveOptions(
@@ -217,15 +220,17 @@ class ScheduleServer:
         return batch
 
     def _dispatch(self, batch: list[_Request]) -> _Inflight:
+        seq = self._next_flight
+        self._next_flight += 1
         tracer = get_tracer()
         dispatch_span = tracer.span(
             "serve.dispatch",
-            {"batch": len(batch)} if tracer.enabled else None,
+            {"batch": len(batch), "flight": seq} if tracer.enabled else None,
         )
         with dispatch_span:
-            return self._dispatch_inner(batch)
+            return self._dispatch_inner(seq, batch)
 
-    def _dispatch_inner(self, batch: list[_Request]) -> _Inflight:
+    def _dispatch_inner(self, seq: int, batch: list[_Request]) -> _Inflight:
         degraded = batch[0].degraded
         cached: list[tuple[_Request, CacheResult]] = []
         device: list[_Request] = []
@@ -258,6 +263,7 @@ class ScheduleServer:
                 np.stack([r.D for r in device]), self.s, self.delta, options
             )
         return _Inflight(
+            seq=seq,
             device_reqs=device,
             pending=pending,
             cached=cached,
@@ -275,7 +281,11 @@ class ScheduleServer:
         tracer = get_tracer()
         install_span = tracer.span(
             "serve.install",
-            {"device": len(flight.device_reqs), "cached": len(flight.cached)}
+            {
+                "device": len(flight.device_reqs),
+                "cached": len(flight.cached),
+                "flight": flight.seq,
+            }
             if tracer.enabled
             else None,
         )
@@ -284,8 +294,10 @@ class ScheduleServer:
 
     def _install_inner(self, flight: _Inflight) -> None:
         reports: list[SolveReport] = []
+        collect_timings: dict[str, float] = {}
         if flight.pending is not None:
             reports = flight.pending.collect()
+            collect_timings = flight.pending.timings
         elif flight.device_reqs:
             options = (
                 self._degraded_options if flight.degraded else self.options
@@ -310,13 +322,14 @@ class ScheduleServer:
             if self.cache is not None and not flight.degraded:
                 self.cache.insert(req.D, rep.schedule, rep.decomposition)
             self._record(
-                req, done_t, device_s,
+                req, flight.seq, done_t, device_s,
                 source="device", makespan=rep.makespan,
                 num_configs=rep.num_configs, report=rep,
+                extra_timings=collect_timings,
             )
         for req, hit in flight.cached:
             self._record(
-                req, done_t, device_s=0.0,
+                req, flight.seq, done_t, device_s=0.0,
                 source=f"cache:{hit.tier}", makespan=hit.makespan,
                 num_configs=hit.num_configs, report=None,
             )
@@ -324,6 +337,7 @@ class ScheduleServer:
     def _record(
         self,
         req: _Request,
+        flight: int,
         done_t: float,
         device_s: float,
         *,
@@ -331,6 +345,7 @@ class ScheduleServer:
         makespan: float,
         num_configs: int,
         report: SolveReport | None,
+        extra_timings: dict[str, float] | None = None,
     ) -> None:
         queue_wait = max(0.0, done_t - req.submit_t - device_s
                          - self.install_latency_s)
@@ -338,6 +353,7 @@ class ScheduleServer:
             "queue_wait_s": queue_wait,
             "device_s": device_s,
             "e2e_s": done_t - req.submit_t,
+            **(extra_timings or {}),
         }
         self.metrics.observe("queue_wait", queue_wait)
         self.metrics.observe("device", device_s)
@@ -351,6 +367,7 @@ class ScheduleServer:
             num_configs=int(num_configs),
             degraded=req.degraded,
             report=report,
+            flight=flight,
             timings=timings,
         )
 

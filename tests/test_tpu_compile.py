@@ -86,8 +86,10 @@ def test_auction_fused_compiles_for_tpu(one_chip, n, B):
     from repro.kernels.auction_fused import fused_auction
 
     def run(W, p0, eps):
+        # with_iters: the rounds of each phase come out of an SMEM block.
         return fused_auction(
-            W, p0, eps, max_iters=default_max_iters(n), use_kernel=True
+            W, p0, eps, max_iters=default_max_iters(n), use_kernel=True,
+            with_iters=True,
         )
 
     lead = (B,) if B else ()
@@ -98,6 +100,9 @@ def test_auction_fused_compiles_for_tpu(one_chip, n, B):
         _spec(lead + (n,), one_chip),
         _spec(lead + (default_num_phases(n),), one_chip),
     )
+    iters = compiled.out_info[3]
+    assert iters.shape == lead + (default_num_phases(n),)
+    assert iters.dtype == jnp.int32
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
