@@ -11,8 +11,10 @@ jittable, ``vmap``-able matchers sharing one bidding engine:
                  alternates row-side and column-side bidding rounds,
                  switching sides whenever the assignment grows. Dual-side
                  bidding breaks the one-sided price wars that sparse
-                 large-n instances can trigger, at ~2 top-2 reductions per
-                 round.
+                 large-n instances can trigger. Each round is one top-2
+                 reduction: a column-side round is the row-side round on
+                 ``W.T`` with the roles swapped, so each lane selects its
+                 side's operands and bids once, batched or not.
     auction_fused  the whole hot loop owned by one fused implementation
                  (``kernels/auction_fused``): with ``use_kernel`` a single
                  Pallas kernel runs bid → price-update → assignment-flip
@@ -23,7 +25,7 @@ jittable, ``vmap``-able matchers sharing one bidding engine:
                  host path. The default matcher at n > 128.
 
 ``auction``/``auction_fr`` share the Pallas ``kernels/auction_bid`` top-2
-reduction via ``use_kernel`` (the reverse rounds call it on ``W.T``);
+reduction via ``use_kernel`` (a column-side round calls it on ``W.T``);
 ``auction_fused`` swaps the whole loop for ``kernels/auction_fused``.
 
 The ε-schedule is n- and spread-aware. Two failure modes of a fixed
@@ -161,8 +163,9 @@ def _forward_round(W, row2col, col2row, prices, profits, eps, use_kernel):
     """One Jacobi bidding round: all unassigned rows bid, columns take max.
 
     Also maintains row profits (``π_i = v2 - ε`` for winners) so the same
-    round serves as one side of the forward-reverse matcher; the plain
-    forward matcher threads a zero array through unchanged cost.
+    round, as ``_bid_round``, serves either side of the forward-reverse
+    matcher; the plain forward matcher threads a zero array through
+    unchanged cost.
     """
     with jax.named_scope("auction.forward_round"):
         return _bid_round(
@@ -195,16 +198,6 @@ def _bid_round(W, row2col, col2row, prices, profits, eps, use_kernel):
     profits = profits.at[winner].set(
         jnp.where(has_bid, v2[safe_winner] - eps, 0.0), mode="drop"
     )
-    return row2col, col2row, prices, profits
-
-
-def _reverse_round(W, row2col, col2row, prices, profits, eps, use_kernel):
-    """Column-side bidding: the forward round on ``W.T`` with roles swapped
-    (prices ↔ profits), sharing the same top-2 reduction."""
-    with jax.named_scope("auction.reverse_round"):
-        col2row, row2col, profits, prices = _bid_round(
-            W.T, col2row, row2col, profits, prices, eps, use_kernel
-        )
     return row2col, col2row, prices, profits
 
 
@@ -333,7 +326,13 @@ def match_auction_fr(
 
     Rows and columns take turns bidding; the side flips whenever a round
     grows the assignment (Bertsekas-Castañón switching rule — the matched
-    count never shrinks, so alternation cannot cycle). ``prices0`` /
+    count never shrinks, so alternation cannot cycle). A column-side round
+    is the row-side round on ``W.T`` with rows ↔ columns and prices ↔
+    profits swapped, so each round selects its side's operands with
+    ``jnp.where`` and runs ``_bid_round`` once. Under ``vmap`` the side is
+    chosen per lane and every lane still runs one round per iteration (a
+    ``lax.cond`` on the side would become a ``select`` that computes both
+    rounds and keeps one). ``prices0`` /
     ``with_prices`` / ``with_iters`` behave as on ``match_auction``
     (warm-started column prices in, final prices and rounds per phase out).
     """
@@ -349,6 +348,8 @@ def match_auction_fr(
         else jnp.asarray(prices0, jnp.float32)
     )
 
+    WT = W.T
+
     def phase(state, eps):
         _, _, prices, profits = state
         row2col = jnp.full((n,), -1, jnp.int32)
@@ -361,12 +362,16 @@ def match_auction_fr(
         def body(c):
             row2col, col2row, prices, profits, fwd, it = c
             before = (row2col >= 0).sum()
-            row2col, col2row, prices, profits = jax.lax.cond(
-                fwd,
-                lambda a: _forward_round(W, *a, eps, use_kernel),
-                lambda a: _reverse_round(W, *a, eps, use_kernel),
-                (row2col, col2row, prices, profits),
+
+            def side(a, b):
+                return jnp.where(fwd, a, b)
+
+            r2c, c2r, p, q = _bid_round(
+                side(W, WT), side(row2col, col2row), side(col2row, row2col),
+                side(prices, profits), side(profits, prices), eps, use_kernel,
             )
+            row2col, col2row = side(r2c, c2r), side(c2r, r2c)
+            prices, profits = side(p, q), side(q, p)
             grew = (row2col >= 0).sum() > before
             return row2col, col2row, prices, profits, fwd ^ grew, it + 1
 

@@ -14,10 +14,10 @@ from __future__ import annotations
 import re
 
 SCOPES = (
-    # DECOMPOSE's matcher call, and the bidding rounds inside it.
+    # DECOMPOSE's matcher call, and ``auction``'s bidding rounds inside it
+    # (``auction_fr``'s rounds carry no scope of their own).
     "spectra.decompose.match",
     "auction.forward_round",
-    "auction.reverse_round",
     # DECOMPOSE's bonus matrix, α and residual updates around the matcher.
     "spectra.decompose.peel",
     "spectra.refine",

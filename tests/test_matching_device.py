@@ -183,3 +183,166 @@ def test_repair_noop_on_tight_decompositions():
     np.testing.assert_allclose(
         np.asarray(plain.alphas), np.asarray(repaired.alphas), atol=1e-6
     )
+
+
+# ------------------------------------- auction_fr: one round per iteration
+
+def _fr_two_branch(W, prices0, use_kernel):
+    """The forward-reverse auction as a per-iteration ``lax.cond`` between a
+    row-side round on ``W`` and a column-side round on ``W.T``: the
+    reference for the single-round loop. Returns ``(perm, prices, rounds
+    per phase)``."""
+    import jax
+
+    from repro.core.jaxopt.matching import (
+        _bid_round,
+        _complete_greedy,
+        _eps_schedule,
+        default_max_iters,
+        default_num_phases,
+    )
+
+    W = W.astype(jnp.float32)
+    n = W.shape[0]
+    max_iters = default_max_iters(n)
+
+    def forward(a, eps):
+        return _bid_round(W, *a, eps, use_kernel)
+
+    def reverse(a, eps):
+        row2col, col2row, prices, profits = a
+        col2row, row2col, profits, prices = _bid_round(
+            W.T, col2row, row2col, profits, prices, eps, use_kernel
+        )
+        return row2col, col2row, prices, profits
+
+    def phase(state, eps):
+        _, _, prices, profits = state
+        row2col = jnp.full((n,), -1, jnp.int32)
+        col2row = jnp.full((n,), -1, jnp.int32)
+
+        def cond(c):
+            return (c[0] < 0).any() & (c[5] < max_iters)
+
+        def body(c):
+            row2col, col2row, prices, profits, fwd, it = c
+            before = (row2col >= 0).sum()
+            row2col, col2row, prices, profits = jax.lax.cond(
+                fwd,
+                lambda a: forward(a, eps),
+                lambda a: reverse(a, eps),
+                (row2col, col2row, prices, profits),
+            )
+            grew = (row2col >= 0).sum() > before
+            return row2col, col2row, prices, profits, fwd ^ grew, it + 1
+
+        c = jax.lax.while_loop(
+            cond, body,
+            (row2col, col2row, prices, profits, jnp.bool_(True), jnp.int32(0)),
+        )
+        return c[:4], c[5]
+
+    state = (
+        jnp.full((n,), -1, jnp.int32),
+        jnp.full((n,), -1, jnp.int32),
+        jnp.asarray(prices0, jnp.float32),
+        jnp.zeros((n,), jnp.float32),
+    )
+    (row2col, col2row, prices, _), iters = jax.jit(
+        lambda s: jax.lax.scan(
+            phase, s, _eps_schedule(W, default_num_phases(n))
+        )
+    )(state)
+    return _complete_greedy(row2col, col2row), prices, iters
+
+
+@pytest.mark.parametrize(
+    "n, use_kernel, warm",
+    [(5, False, False), (37, False, False), (5, True, False), (37, False, True)],
+)
+def test_auction_fr_vmapped_lanes_match_two_branch_reference(n, use_kernel, warm):
+    """Lanes on different sides in the same iteration each run exactly the
+    round the per-lane ``cond`` reference runs: bit-identical perms, prices
+    and rounds per phase."""
+    import jax
+
+    from repro.core.jaxopt.matching import match_auction_fr
+
+    rng = np.random.default_rng(1000 + n)
+    Ws = jnp.asarray(
+        np.stack([
+            rng.random((n, n)),
+            rng.integers(0, 50, (n, n)),
+            rng.random((n, n)) * (rng.random((n, n)) < 0.3),
+        ]),
+        jnp.float32,
+    )
+    p0 = jnp.asarray(
+        rng.random((3, n)) if warm else np.zeros((3, n)), jnp.float32
+    )
+    perm, conv, prices, iters = jax.vmap(
+        lambda W, p: match_auction_fr(
+            W, prices0=p, use_kernel=use_kernel, with_prices=True,
+            with_iters=True,
+        )
+    )(Ws, p0)
+    assert bool(conv.all())
+    for lane in range(3):
+        ref = _fr_two_branch(Ws[lane], p0[lane], use_kernel)
+        for name, got, want in zip(
+            ("perm", "prices", "rounds"), (perm, prices, iters), ref
+        ):
+            np.testing.assert_array_equal(
+                np.asarray(got[lane]), np.asarray(want),
+                err_msg=f"lane {lane}: {name}",
+            )
+    # The lanes diverge, so the batch runs them on different sides.
+    assert len({tuple(np.asarray(r)) for r in iters}) > 1
+
+
+def _count_primitive(jaxpr, name: str) -> int:
+    """Occurrences of primitive ``name`` in ``jaxpr`` and every sub-jaxpr."""
+    count = 0
+    for eqn in jaxpr.eqns:
+        count += eqn.primitive.name == name
+        for sub in _sub_jaxprs(eqn.params.values()):
+            count += _count_primitive(sub, name)
+    return count
+
+
+def _sub_jaxprs(values):
+    for v in values:
+        if isinstance(v, (list, tuple)):
+            yield from _sub_jaxprs(v)
+        elif hasattr(v, "eqns"):
+            yield v
+        elif hasattr(v, "jaxpr") and hasattr(v.jaxpr, "eqns"):
+            yield v.jaxpr
+
+
+def _while_bodies(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "while":
+            yield eqn.params["body_jaxpr"].jaxpr
+        for sub in _sub_jaxprs(eqn.params.values()):
+            yield from _while_bodies(sub)
+
+
+@pytest.mark.parametrize("batched", [True, False])
+def test_auction_fr_bids_once_per_iteration(batched):
+    """The bidding ``while`` body calls the top-2 kernel once, batched or
+    not: no second round for the other side."""
+    import functools
+
+    import jax
+
+    from repro.core.jaxopt.matching import match_auction_fr
+
+    fn = functools.partial(match_auction_fr, use_kernel=True)
+    W = jnp.zeros((3, 8, 8) if batched else (8, 8), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.vmap(fn) if batched else fn)(W).jaxpr
+    bodies = [
+        b for b in _while_bodies(jaxpr) if _count_primitive(b, "pallas_call")
+    ]
+    assert len(bodies) == 1
+    assert _count_primitive(bodies[0], "pallas_call") == 1

@@ -65,7 +65,7 @@ def test_op_scopes_takes_innermost_scope():
     text = "\n".join([
         '  %fusion.3 = f32[2]{0} fusion(%p), kind=kLoop, calls=%fc, '
         'metadata={op_name="jit(f)/vmap(spectra.decompose.match)/while/body/'
-        'jit(match_auction_fr)/auction.reverse_round/max" source_line=1}',
+        'jit(match_auction)/auction.forward_round/max" source_line=1}',
         '  ROOT %add.1 = f32[] add(%a, %b), metadata={op_name='
         '"jit(f)/vmap(spectra.lb)/add"}',
         '  %copy.2 = f32[2]{0} copy(%x), metadata={op_name="jit(f)/vmap()/copy"}',
@@ -73,19 +73,26 @@ def test_op_scopes_takes_innermost_scope():
         '"jit(f)/spectra.lbx/neg"}',
     ])
     assert op_scopes(text) == {
-        "fusion.3": "auction.reverse_round",
+        "fusion.3": "auction.forward_round",
         "add.1": "spectra.lb",
     }
 
 
-def test_program_text_scopes_every_stage():
-    text = program_text(2, N, 4, SolveOptions(extra={"matcher": "auction_fr"}))
+@pytest.mark.parametrize(
+    "matcher, round_scopes",
+    [("auction", {"auction.forward_round"}), ("auction_fr", set())],
+)
+def test_program_text_scopes_every_stage(matcher, round_scopes):
+    """``auction``'s rounds carry their own scope; ``auction_fr``'s single
+    round per iteration falls under ``spectra.decompose.match``."""
+    text = program_text(2, N, 4, SolveOptions(extra={"matcher": matcher}))
     found = set(op_scopes(text).values())
     assert {
         "spectra.decompose.match", "spectra.decompose.peel",
-        "auction.forward_round", "auction.reverse_round",
         "spectra.refine", "spectra.lpt", "spectra.equalize", "spectra.lb",
-    } <= found
+    } | round_scopes <= found
+    assert found & {"auction.forward_round"} == round_scopes
+    assert "auction.reverse_round" not in text
     assert found <= set(SCOPES)
 
 
